@@ -71,25 +71,26 @@ def span_seconds(events, name: str) -> float:
 
 
 def run_metadata() -> dict:
-    """Environment stamp for BENCH_*.json reports (DESIGN.md §11)."""
+    """Environment stamp for BENCH_*.json reports (DESIGN.md §11): host,
+    toolchain and the device every number in the report was measured on."""
     import datetime
     import platform
     import socket
-    meta = {
+
+    import jax
+    import jaxlib
+    dev = jax.devices()[0]
+    return {
         "hostname": socket.gethostname(),
         "python": platform.python_version(),
         "timestamp_utc": datetime.datetime.now(
             datetime.timezone.utc).isoformat(timespec="seconds"),
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": jax.device_count(),
     }
-    try:
-        import jax
-        import jaxlib
-        meta.update(jax=jax.__version__, jaxlib=jaxlib.__version__,
-                    backend=jax.default_backend(),
-                    device_count=jax.device_count())
-    except Exception:  # pragma: no cover - jax is a hard dep in practice
-        pass
-    return meta
 
 
 def row(name: str, us: float, derived) -> str:

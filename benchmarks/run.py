@@ -18,6 +18,9 @@ serve timeline) — the CI perf-trajectory records.
   over N fake CPU devices);
 * ``JAX_ENABLE_X64=0`` / ``JAX_DEFAULT_DTYPE_BITS=32`` defaulted (the
   engine is f32/int32 end to end);
+* the persistent compilation cache is turned on before the first compile
+  (``repro.launch.cache``: ``$JAX_COMPILATION_CACHE_DIR`` if set, else
+  ``.jax_cache/`` in the checkout);
 * ``--tcmalloc`` → re-exec with ``LD_PRELOAD=libtcmalloc`` and a high
   ``TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD`` — glibc malloc returns the
   multi-GB coarsening arenas to the OS poorly at 1M+ vertices.
@@ -30,8 +33,9 @@ import sys
 # `python benchmarks/run.py` puts benchmarks/ (not the repo root) on
 # sys.path — pin the root so `from benchmarks import …` always resolves
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if _ROOT not in sys.path:
-    sys.path.insert(0, _ROOT)
+for _p in (_ROOT, os.path.join(_ROOT, "src")):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
 
 _TCMALLOC = "/usr/lib/x86_64-linux-gnu/libtcmalloc.so.4"
 
@@ -119,6 +123,8 @@ def main() -> None:
 
 if __name__ == "__main__":
     _args = _setup_env(sys.argv[1:])
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if "--scale-smoke" in _args:
         scale(smoke_run=True)
     elif "--scale" in _args:

@@ -35,7 +35,7 @@ _SEED = 0xA11A
 
 
 def _eval_closed(closed, flat_args):
-    import jax.core as core
+    from jax.extend import core
     return core.jaxpr_as_fun(closed)(*flat_args)
 
 

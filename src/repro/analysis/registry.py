@@ -198,7 +198,7 @@ def _build_cluster_lp():
     key = np.asarray(jax.random.PRNGKey(7))
 
     def fn(coo, labs, cap, key):
-        return L._cluster_lp_jit(coo, labs, cap, key, 4)
+        return L._cluster_lp(coo, labs, cap, key, 4)
     return fn, (coo, labs, cap, key)
 
 

@@ -5,17 +5,19 @@ of `hypergraph/dist.py`, the memetic ring migration) and runs a forward
 dataflow analysis over the body, computing for every intermediate the set
 of mesh axes it is *shard-varying* over:
 
-  * body inputs seed from ``in_names`` (an input split over axis a is
+  * body inputs seed from ``in_specs`` (an input split over axis a is
     varying over a; a replicated input over nothing);
   * ``psum``/``pmin``/``pmax``/``all_gather`` over axis a *remove* a
     (the value becomes replicated over a);
+  * ``pvary`` over axis a (inserted by ``check_vma=True`` tracing)
+    *introduces* a, as ``axis_index`` does;
   * ``ppermute``/``all_to_all`` keep the varying set (data moves between
     shards but stays shard-dependent);
   * ``axis_index`` *introduces* its axis;
   * everything else unions its inputs; scan/while carries run to fixpoint;
     a shard-varying cond predicate taints every branch output.
 
-Violations: a body output whose varying set exceeds what ``out_names``
+Violations: a body output whose varying set exceeds what ``out_specs``
 claims (the protocol requires replication there — with ``check_vma=False``
 jax itself won't catch it and each shard would silently hold a different
 value), and any collective whose axis name is not an axis of the mesh.
@@ -24,7 +26,7 @@ from __future__ import annotations
 
 from typing import Dict, FrozenSet, List, Sequence
 
-import jax.core as core
+from jax.extend import core
 
 from repro.analysis.findings import Finding
 from repro.analysis.tracing import TracedEntry, eqn_label, iter_eqns
@@ -32,13 +34,16 @@ from repro.analysis.tracing import TracedEntry, eqn_label, iter_eqns
 EMPTY: FrozenSet[str] = frozenset()
 
 #: collectives that make their output replicated over the named axes
-_REDUCING = ("psum", "pmin", "pmax", "all_gather")
+_REDUCING = ("psum", "psum_invariant", "pmin", "pmax", "all_gather",
+             "all_gather_invariant")
 #: collectives that permute shard-varying data (varying in, varying out)
 _PERMUTING = ("ppermute", "pshuffle", "all_to_all")
 
 
-def _flat_axes(names: dict) -> FrozenSet[str]:
-    return frozenset(ax for axes in names.values() for ax in axes)
+def _flat_axes(spec) -> FrozenSet[str]:
+    """Mesh axes a ``PartitionSpec`` splits over (entries are None, an
+    axis name, or a tuple of axis names)."""
+    return frozenset(ax for entry in spec for ax in _named_axes(entry))
 
 
 def _named_axes(value) -> List[str]:
@@ -83,9 +88,9 @@ class _Dataflow:
                 axes = _named_axes(eqn.params.get("axis_name", ()))
                 self._bad_axis(axes, prim, here)
                 res = union
-            elif prim == "axis_index":
-                ax = eqn.params.get("axis_name")
-                axes = _named_axes(ax)
+            elif prim in ("axis_index", "pvary"):
+                axes = _named_axes(eqn.params.get(
+                    "axes", eqn.params.get("axis_name", ())))
                 self._bad_axis(axes, prim, here)
                 res = union | (frozenset(axes) & self.mesh_axes)
             elif prim == "scan":
@@ -97,7 +102,7 @@ class _Dataflow:
             elif prim == "cond":
                 self._cond(eqn, ins, env, here)
                 continue
-            elif prim == "pjit":
+            elif prim == "jit":
                 body = eqn.params["jaxpr"].jaxpr
                 sub: Dict[core.Var, FrozenSet[str]] = {}
                 for var, ax in zip(body.invars, ins):
@@ -199,18 +204,17 @@ def check_spmd(traced: TracedEntry, entry) -> List[Finding]:
         mesh = eqn.params["mesh"]
         mesh_axes = frozenset(getattr(mesh, "axis_names", ()))
         body = eqn.params["jaxpr"]
-        in_names = eqn.params["in_names"]
-        out_names = eqn.params["out_names"]
         flow = _Dataflow(mesh_axes, entry.name)
         env: Dict[core.Var, FrozenSet[str]] = {}
-        for var, names in zip(body.invars, in_names):
-            env[var] = _flat_axes(names)
+        for var, spec in zip(body.invars, eqn.params["in_specs"]):
+            env[var] = _flat_axes(spec)
         flow.run(body, env, site.path)
         findings.extend(flow.findings)
-        for i, (var, names) in enumerate(zip(body.outvars, out_names)):
+        for i, (var, spec) in enumerate(zip(body.outvars,
+                                            eqn.params["out_specs"])):
             if isinstance(var, core.Literal):
                 continue
-            claimed = _flat_axes(names)
+            claimed = _flat_axes(spec)
             extra = env.get(var, EMPTY) - claimed
             if extra:
                 findings.append(Finding(

@@ -3,7 +3,7 @@
 `trace_entry` runs `jax.make_jaxpr` on an entry point's canonical inputs
 (registry.py) — abstract evaluation only, nothing is compiled or
 executed.  The walker yields every equation in the program together with
-a human-readable path ("scan[3].body/pjit[0]{_refine_scan}") and the
+a human-readable path ("scan[3].body/jit[0]{_refine_scan}") and the
 loop/shard_map context the checkers key off.
 """
 from __future__ import annotations
@@ -12,7 +12,8 @@ import dataclasses
 from typing import Any, Iterator, List, Optional, Sequence, Tuple
 
 import jax
-import jax.core as core
+import jax.core as jcore
+from jax.extend import core
 
 #: primitives whose body jaxprs execute repeatedly (hot-loop context)
 LOOP_PRIMITIVES = ("scan", "while")
@@ -47,7 +48,7 @@ def _jaxpr_of(value) -> Optional[core.Jaxpr]:
 def sub_jaxprs(eqn: core.JaxprEqn) -> Iterator[Tuple[str, core.Jaxpr]]:
     """Yield (param name, body jaxpr) for every sub-jaxpr of an equation.
 
-    Covers pjit/scan/while (`jaxpr` as ClosedJaxpr or Jaxpr), cond
+    Covers jit/scan/while (`jaxpr` as ClosedJaxpr or Jaxpr), cond
     (`branches` tuple), and custom-call params that carry jaxprs.
     """
     for pname, value in eqn.params.items():
@@ -71,7 +72,7 @@ def eqn_label(eqn: core.JaxprEqn, index: int) -> str:
 @dataclasses.dataclass(frozen=True)
 class EqnSite:
     eqn: core.JaxprEqn
-    path: str                 # "scan[2].body/pjit[0]{foo}"
+    path: str                 # "scan[2].body/jit[0]{foo}"
     in_loop: bool             # inside a scan/while body
     loop_depth: int
 
@@ -93,7 +94,7 @@ def iter_eqns(jaxpr: core.Jaxpr, path: str = "", in_loop: bool = False,
                 loop_depth=loop_depth + (1 if body_is_loop else 0))
 
 
-def scan_carry_avals(eqn: core.JaxprEqn) -> Sequence[core.AbstractValue]:
+def scan_carry_avals(eqn: core.JaxprEqn) -> Sequence[jcore.AbstractValue]:
     """Carry avals of a scan equation (body-jaxpr invars, post-consts)."""
     assert eqn.primitive.name == "scan"
     nc = eqn.params["num_consts"]
@@ -102,12 +103,12 @@ def scan_carry_avals(eqn: core.JaxprEqn) -> Sequence[core.AbstractValue]:
     return [v.aval for v in body.invars[nc:nc + ncarry]]
 
 
-def all_avals(jaxpr: core.Jaxpr) -> Iterator[Tuple[str, core.AbstractValue]]:
+def all_avals(jaxpr: core.Jaxpr) -> Iterator[Tuple[str, jcore.AbstractValue]]:
     """Every aval in the program with a location tag (recursive)."""
     for v in jaxpr.invars:
         yield "invar", v.aval
     for site in iter_eqns(jaxpr):
         for v in site.eqn.outvars:
-            if isinstance(v, core.DropVar):
+            if isinstance(v, jcore.DropVar):
                 continue
             yield site.path, v.aval

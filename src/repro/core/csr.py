@@ -260,6 +260,17 @@ class CooGraph:
         return self.vwgt.shape[0]
 
 
+def neighbour_any(g: CooGraph, mask: jax.Array,
+                  ell: Optional[EllGraph] = None) -> jax.Array:
+    """(n_pad,) bool: some neighbour over a positive-weight edge has
+    ``mask`` set.  With the ELL view (same graph, same n_pad) this is a
+    row gather; the COO fallback is a scatter, which the TPU compiler
+    builds far more slowly at large n."""
+    if ell is not None:
+        return jnp.any(mask[ell.nbr] & (ell.wgt > 0), axis=1)
+    return jnp.zeros((g.n_pad,), bool).at[g.dst].max(mask[g.src] & (g.w > 0))
+
+
 def to_ell(g: Graph, row_tile: int = 128, d_mult: int = 8,
            dmax_cap: Optional[int] = None) -> EllGraph:
     """CSR → padded ELL. ``dmax_cap`` truncates hub rows (heaviest edges kept)."""

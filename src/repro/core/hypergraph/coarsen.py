@@ -36,25 +36,24 @@ def clique_expansion(hg: Hypergraph, max_net_size: int = 64,
     their first pin (linear #edges) when ``large_net_fallback``; with the
     fallback off they are skipped entirely (the pre-PR-2 behaviour).
     """
-    us, vs, ws = [], [], []
     esz = hg.net_sizes()
-    for e in range(hg.m):
-        sz = int(esz[e])
-        if sz < 2:
+    rating = np.maximum(1, np.round(scale * hg.ewgt / np.maximum(esz - 1, 1))
+                        ).astype(np.int64)
+    us, vs, ws = [], [], []
+    # one vectorized block per net size: the (nets, size) pin matrix
+    for sz in np.unique(esz[esz >= 2]):
+        sz = int(sz)
+        if sz > max_net_size and not large_net_fallback:
             continue
-        pins = hg.net_pins(e)
-        r = max(1, int(round(scale * int(hg.ewgt[e]) / (sz - 1))))
+        nets = np.flatnonzero(esz == sz)
+        pins = hg.eind[hg.eptr[nets][:, None] + np.arange(sz)]
         if sz > max_net_size:
-            if not large_net_fallback:
-                continue
             # star fallback: hub = first pin, one edge per remaining pin
-            us.append(np.full(sz - 1, pins[0], dtype=np.int64))
-            vs.append(pins[1:])
-            ws.append(np.full(sz - 1, r, dtype=np.int64))
-            continue
-        iu, iv = np.triu_indices(sz, k=1)
-        us.append(pins[iu]); vs.append(pins[iv])
-        ws.append(np.full(len(iu), r, dtype=np.int64))
+            iu, iv = np.zeros(sz - 1, np.int64), np.arange(1, sz)
+        else:
+            iu, iv = np.triu_indices(sz, k=1)
+        us.append(pins[:, iu].ravel()); vs.append(pins[:, iv].ravel())
+        ws.append(np.repeat(rating[nets], len(iu)))
     if not us:
         return Graph.from_edges(hg.n, [], [], vwgt=hg.vwgt)
     return Graph.from_edges(hg.n, np.concatenate(us), np.concatenate(vs),

@@ -18,10 +18,11 @@ the all-gather SPMD partitioning inserts).  Each refinement round:
      *column*, psum'd over ``nets`` only (a net's pins for one column all
      live on one device, so its contribution is computed exactly once);
   3. moves are proposed with the same noise/parity split as the sequential
-     refiner, and each shard applies capped acceptance on its *owned
-     vertex slice* against its share of the psum'd global remaining
-     capacity — so the balance constraint holds globally without a
-     sequential arbiter (the core/parhip.py recipe).
+     refiner and accepted by the sequential refiner's rule: each shard
+     searches the per-block prefix boundary of its owned vertex slice, the
+     per-block partial inflows psum'd over the mesh at every step
+     (`lp.accept_prefix`) — so the balance constraint holds globally and
+     moves compete for room by priority across the mesh.
 
 Coarsening is device-resident too: a distributed LP-clustering round
 (deterministic min-label tie-breaks, integer fixed-point ratings so every
@@ -49,9 +50,8 @@ from typing import List, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro import obs
 from repro.core.csr import _pow2_pad
@@ -59,10 +59,13 @@ from repro.core import lp as lp_mod
 from repro.core.hypergraph.container import Hypergraph
 from repro.core.hypergraph import metrics as M
 from repro.core.hypergraph.coarsen import RATING_SCALE
+from repro.core.sorting import bit_width, lexsort
 
-# psums issued per distributed refinement round: the Φ(e,b) histogram plus
-# two gain partials (aff/rem for km1, joins/breaks for cut-net)
-_PSUMS_PER_ROUND = 3
+def _psums_per_round(n_pad: int) -> int:
+    """psums issued per distributed refinement round: the Φ(e,b) histogram,
+    two gain partials (aff/rem for km1, joins/breaks for cut-net) and one
+    per-block inflow per step of the acceptance's bit search."""
+    return 3 + 32 + n_pad.bit_length()
 
 _NEG = -1e30
 _NOISE = 1e-4
@@ -255,8 +258,7 @@ def _dist_wtot_local(pv, pe, mask, netw, vwgt, ax_n, ax_v):
 
 def _dist_round_local(pv, pe, mask, netw, esize, vwgt, wtot, labels, sizes,
                       cap, key, parity, force, rows_v: int, n_col: int,
-                      e_rows: int, k: int, s_nets: int, s_verts: int,
-                      ax_n, ax_v, objective: str):
+                      e_rows: int, k: int, ax_n, ax_v, objective: str):
     """One distributed LP round, run per shard under shard_map.
 
     ``labels`` is the full replicated vector; pin arrays arrive as (1, ·)
@@ -270,7 +272,6 @@ def _dist_round_local(pv, pe, mask, netw, esize, vwgt, wtot, labels, sizes,
     pv, pe, mask = (a.reshape(-1) for a in (pv, pe, mask))
     ie = _idx(ax_n)
     jv = _idx(ax_v)
-    me = jv * s_nets + ie
     n_pad = labels.shape[0]
     p_loc = pv.shape[0]
     lab_pin = labels[pv].astype(jnp.int32)
@@ -323,30 +324,25 @@ def _dist_round_local(pv, pe, mask, netw, esize, vwgt, wtot, labels, sizes,
     want = want & node_par
     proposal = jnp.where(want, best_tgt, labels_col)
     pri = jnp.where(want, best_gain, _NEG)
-    # Per-shard capped acceptance on the owned vertex slice against the
-    # psum'd global size constraint.  The split of the remaining room is
-    # contention-aware: per block, if the global proposed inflow (demand —
-    # proposals are nets-replicated, so one verts-psum makes it global)
-    # fits the room, every shard may accept (total <= demand <= room);
-    # otherwise only a rotating owner shard gets the room (total <= room).
-    # Either way the global constraint holds without a sequential arbiter,
-    # and an even room/S split — which rounds to zero headroom for
-    # unit-weight moves at tight eps — is avoided.  With one shard the
-    # owner is always shard 0, so the round stays bit-identical to the
-    # sequential oracle.
-    vw_mov = jnp.where(proposal != labels_col, vw_col, 0.0)
-    demand = _psum(jnp.zeros((k,), jnp.float32).at[proposal].add(vw_mov),
-                   ax_v)
-    uncontended = demand <= cap - sizes
-    owner_b = (jnp.arange(k) + parity) % (s_nets * s_verts) == me
-    cap_local = jnp.where(uncontended | owner_b, cap, sizes)
+    # Capped acceptance with the sequential decision: per block, movers
+    # are taken by (priority desc, global index asc) while the block fits.
+    # Each shard searches the prefix boundary on its owned vertex slice
+    # and the per-block partial inflows are psum'd at every step, so all
+    # shards reach the same global boundary without gathering proposals —
+    # moves compete for a block's room across the whole mesh, whatever the
+    # device count or layout.
     off = ie * rows_v
     lab_own = jax.lax.dynamic_slice(labels_col, (off,), (rows_v,))
     prop_own = jax.lax.dynamic_slice(proposal, (off,), (rows_v,))
     vw_own = jax.lax.dynamic_slice(vw_col, (off,), (rows_v,))
     pri_own = jax.lax.dynamic_slice(pri, (off,), (rows_v,))
-    new_own = lp_mod.capped_accept(lab_own, prop_own, vw_own, sizes,
-                                   cap_local, pri_own)
+    moving = prop_own != lab_own
+    ok = lp_mod.accept_prefix(
+        prop_own.astype(jnp.int32), pri_own, jnp.where(moving, vw_own, 0.0),
+        jv * n_col + off + jnp.arange(rows_v, dtype=jnp.int32),
+        n_pad.bit_length(), sizes, cap,
+        total=lambda x: _psum(_psum(x, ax_n), ax_v))
+    new_own = jnp.where(moving & ok, prop_own, lab_own)
     return new_own, obj
 
 
@@ -357,12 +353,10 @@ def _parhyp_refine_jit(mesh: Mesh, pv, pe, mask, netw, esize, vwgt,
                        labels0, cap, key, force, rows_v: int, n_col: int,
                        e_rows: int, k: int, rounds: int, objective: str):
     ax_n, ax_v = _mesh_axes(mesh)
-    s_nets, s_verts = _mesh_extents(mesh)
     spec_p, spec_v, spec_r = _specs(ax_n, ax_v)
     round_fn = shard_map(
         functools.partial(_dist_round_local, rows_v=rows_v, n_col=n_col,
-                          e_rows=e_rows, k=k, s_nets=s_nets,
-                          s_verts=s_verts, ax_n=ax_n, ax_v=ax_v,
+                          e_rows=e_rows, k=k, ax_n=ax_n, ax_v=ax_v,
                           objective=objective),
         mesh=mesh,
         in_specs=(spec_p, spec_p, spec_p, spec_r, spec_r, spec_r, spec_r,
@@ -460,8 +454,9 @@ def parhyp_refine(hg: Hypergraph, part: np.ndarray, k: int,
                                        k_pad, rounds, objective)
         out = np.asarray(out, dtype=np.int64)[:hg.n]
     rec.count("parhyp/dist_rounds", rounds)
-    # per round: Φ + two gain partials; plus the one-off wtot and final Φ
-    rec.count("parhyp/psum_rounds", _PSUMS_PER_ROUND * rounds + 2)
+    # per round: Φ, two gain partials, the acceptance's inflows; plus the
+    # one-off wtot and final Φ
+    rec.count("parhyp/psum_rounds", _psums_per_round(sh.n_pad) * rounds + 2)
     score = M.connectivity if objective == "km1" else M.cut_net
     if score(hg, out) <= score(hg, part) or force_balance:
         return out
@@ -508,7 +503,8 @@ def _cluster_round_local(pv, pe, mask, netw, esize, vwgt, labels, capv,
     dead = jnp.where(live, 0, 1)
     lab_p = jnp.where(live, labels[pv].astype(jnp.int32), n_pad)
     # pass 1: per-(net, label) run counts → per-net top-2 labels
-    order = jnp.lexsort((lab_p, pe_loc, dead))
+    order = lexsort((lab_p, pe_loc, dead),
+                    (bit_width(n_pad), bit_width(e_rows - 1), 1))
     pe_s = pe_loc[order]
     lab_s = lab_p[order]
     live_s = live[order]
@@ -541,7 +537,8 @@ def _cluster_round_local(pv, pe, mask, netw, esize, vwgt, labels, capv,
     # pass 2: aggregate candidate affinity per (vertex, candidate)
     has_cand = live & (cand < n_pad)
     dead2 = jnp.where(has_cand, 0, 1)
-    order2 = jnp.lexsort((cand, pv_loc, dead2))
+    order2 = lexsort((cand, pv_loc, dead2),
+                     (bit_width(n_pad), bit_width(n_col - 1), 1))
     pv2 = pv_loc[order2]
     cand_s = cand[order2]
     live2 = dead2[order2] == 0
@@ -598,11 +595,11 @@ def _cluster_round_local(pv, pe, mask, netw, esize, vwgt, labels, capv,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("rows_v", "n_col", "e_rows", "iters",
-                                    "mesh"))
-def _parhyp_cluster_jit(mesh: Mesh, pv, pe, mask, netw, esize, vwgt,
-                        labels0, capv, parity0, rows_v: int, n_col: int,
-                        e_rows: int, iters: int):
+                   static_argnames=("rows_v", "n_col", "e_rows", "mesh"))
+def _parhyp_cluster_round_jit(mesh: Mesh, pv, pe, mask, netw, esize, vwgt,
+                              labels, capv, parity, moved, rows_v: int,
+                              n_col: int, e_rows: int):
+    """One distributed clustering round → (labels, ``moved`` + its moves)."""
     ax_n, ax_v = _mesh_axes(mesh)
     s_nets, s_verts = _mesh_extents(mesh)
     spec_p, spec_v, spec_r = _specs(ax_n, ax_v)
@@ -616,16 +613,25 @@ def _parhyp_cluster_jit(mesh: Mesh, pv, pe, mask, netw, esize, vwgt,
         out_specs=(spec_v, spec_r),
         check_vma=False,
     )
+    labels, m = round_fn(pv, pe, mask, netw, esize, vwgt, labels, capv,
+                         parity)
+    return labels, moved + m
 
-    def body(carry, _):
-        labels, parity = carry
-        new_labels, moved = round_fn(pv, pe, mask, netw, esize, vwgt,
-                                     labels, capv, parity)
-        return (new_labels, parity + 1), moved
 
-    (labels, _), moved = jax.lax.scan(body, (labels0, parity0), None,
-                                      length=iters)
-    return labels, jnp.sum(moved)
+def _parhyp_cluster_jit(mesh: Mesh, pv, pe, mask, netw, esize, vwgt,
+                        labels0, capv, parity0, rows_v: int, n_col: int,
+                        e_rows: int, iters: int):
+    """``iters`` distributed clustering rounds → (labels, total moved).
+
+    One compiled round is dispatched per iteration: the TPU compiler
+    builds the round inside a device loop several times more slowly than
+    alone (the clustering LP of core/lp.py does the same)."""
+    labels, moved = labels0, jnp.int32(0)
+    for i in range(iters):
+        labels, moved = _parhyp_cluster_round_jit(
+            mesh, pv, pe, mask, netw, esize, vwgt, labels, capv,
+            jnp.int32(parity0 + i), moved, rows_v, n_col, e_rows)
+    return labels, moved
 
 
 def _compact_labels(labels, vwgt, n_col: int):
@@ -641,7 +647,7 @@ def _compact_labels(labels, vwgt, n_col: int):
     pr = cvw_l > 0
     col = jnp.arange(n_pad) // n_col
     key = col * (2 * n_col) + jnp.where(pr, 0, n_col)
-    perm = jnp.argsort(key, stable=True)
+    perm = lexsort((key,), (bit_width(2 * n_pad),))
     newid = jnp.zeros((n_pad,), jnp.int32).at[perm].set(
         jnp.arange(n_pad, dtype=jnp.int32))
     coarse_of = newid[labels]
@@ -669,7 +675,8 @@ def _contract_pins_local(pv, pe, mask, netw, coarse_of, n_col: int,
     pvn = jnp.where(live, coarse_of[pv], n_pad - 1)
     pe_loc = jnp.clip(pe - ie * e_rows, 0, e_rows - 1)
     dead = jnp.where(live, 0, 1)
-    order = jnp.lexsort((pvn, pe_loc, dead))
+    order = lexsort((pvn, pe_loc, dead),
+                    (bit_width(n_pad - 1), bit_width(e_rows - 1), 1))
     pe_s = pe_loc[order]
     pvn_s = pvn[order]
     live_s = live[order]
@@ -760,7 +767,7 @@ def _device_hierarchy(sh: ShardedHypergraph, mesh: Mesh, cfg, k: int,
         with rec.span("parhyp_coarsen", level=lvl, n=n_cur):
             labels, _ = _parhyp_cluster_jit(
                 mesh, L.pv, L.pe, L.mask, L.netw, L.esize, L.vwgt,
-                labels0, capv, jnp.int32(lvl), sh.rows_v, sh.n_col,
+                labels0, capv, lvl, sh.rows_v, sh.n_col,
                 sh.e_rows, cfg.lp_iters)
             (pv2, pe2, mask2, netw2, esize2, cvw, coarse_of,
              nc, hi) = _parhyp_contract_jit(mesh, L.pv, L.pe, L.mask,
@@ -908,7 +915,8 @@ def _parhyp_device(hg: Hypergraph, k: int, eps: float, cfg, rounds: int,
                 jnp.asarray(False), sh.rows_v, sh.n_col, sh.e_rows,
                 k_pad, rounds, objective)
             rec.count("parhyp/dist_rounds", rounds)
-            rec.count("parhyp/psum_rounds", _PSUMS_PER_ROUND * rounds + 2)
+            rec.count("parhyp/psum_rounds",
+                      _psums_per_round(sh.n_pad) * rounds + 2)
             if not bool(feas):
                 # forced-balance repair on the SAME device level views —
                 # no re-sharding from the host container

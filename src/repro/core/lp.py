@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.csr import CooGraph, Graph, to_coo
+from repro.core.sorting import bit_width, lexsort, sort_free
 
 _NEG = -1e30
 _NOISE = 1e-4          # random tie-break amplitude
@@ -37,17 +38,88 @@ _GAIN_EPS = 1e-3       # strictly-positive-gain threshold (> noise)
 # capped acceptance: apply proposed moves without exceeding target capacity
 # ---------------------------------------------------------------------------
 
-def capped_accept(labels: jax.Array, proposal: jax.Array, vwgt: jax.Array,
-                  sizes: jax.Array, cap: jax.Array,
-                  priority: jax.Array) -> jax.Array:
-    """Accept moves in priority order (desc) per target until capacity.
+def _order_key(x: jax.Array) -> jax.Array:
+    """float32 → uint32 preserving order (−0.0 ranks with 0.0, as in jnp's
+    sorts); finite inputs only."""
+    x = jnp.where(x == 0, jnp.zeros_like(x), x)
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
 
-    Guarantee: for every target t, size[t] + accepted_inflow[t] <= cap[t]
-    (outflow ignored → conservative).  Returns new labels.
+
+#: up to this many targets the bit search keeps one boundary per target
+#: and sums inflows in one dense (targets × movers) reduction, with no
+#: gather or scatter; above it (clustering: one target per node) each mover
+#: carries its target's boundary, and a bit costs one scatter-add and one
+#: gather.
+_DENSE_TARGETS = 128
+
+
+def accept_prefix(tgt: jax.Array, priority: jax.Array, vw: jax.Array,
+                  idx: jax.Array, n_bits: int, sizes: jax.Array,
+                  cap: jax.Array, total=lambda inflow: inflow) -> jax.Array:
+    """Sort-free capped acceptance → (m,) bool, the accepted movers.
+
+    Per target t the movers are ordered by (priority desc, ``idx`` asc)
+    and the longest prefix with ``sizes[t] + inflow <= cap[t]`` is taken
+    (``vw`` is 0 for non-movers).  The boundary of that prefix — a
+    priority key L, then an index J among movers with key == L — is built
+    bit by bit as the largest whose inflow still fits: one per-target sum
+    per bit, in one scan.  ``idx`` holds distinct ints below 2**n_bits;
+    ``total`` combines per-target partial sums when the movers are spread
+    over several shards (a psum), so every shard takes the same decision.
     """
-    n = labels.shape[0]
-    moving = proposal != labels
-    vw = jnp.where(moving, vwgt, 0.0)
+    k = sizes.shape[0]
+    key = _order_key(-priority)
+
+    if k <= _DENSE_TARGETS:
+        t_ids = jnp.arange(k, dtype=tgt.dtype)[:, None]
+
+        def below(lk, jk):                              # (k, m)
+            lt, jt = lk[:, None], jk[:, None]
+            return (tgt[None, :] == t_ids) & (
+                (key[None, :] < lt) | ((key[None, :] == lt)
+                                       & (idx[None, :] < jt)))
+
+        def fits(lk, jk):
+            inflow = jnp.sum(jnp.where(below(lk, jk), vw[None, :], 0.0),
+                             axis=1)
+            return sizes + total(inflow) <= cap
+
+        def taken(lk, jk):
+            return jnp.any(below(lk, jk), axis=0)
+
+        shape = (k,)
+    else:
+        def taken(lt, jt):
+            return (key < lt) | ((key == lt) & (idx < jt))
+
+        def fits(lt, jt):
+            inflow = jnp.zeros((k,), vw.dtype).at[tgt].add(
+                jnp.where(taken(lt, jt), vw, 0.0))
+            return (sizes + total(inflow) <= cap)[tgt]
+
+        shape = tgt.shape
+
+    def step(carry, s):
+        lk, jk = carry
+        on_key = s < 32
+        bit_l = jnp.where(on_key, jnp.uint32(1) << (31 - s).astype(jnp.uint32),
+                          jnp.uint32(0))
+        bit_j = jnp.where(on_key, 0, jnp.int32(1) << jnp.maximum(
+            n_bits - 1 - (s - 32), 0))
+        cl, cj = lk | bit_l, jk | bit_j
+        ok = fits(cl, cj)
+        return (jnp.where(ok, cl, lk), jnp.where(ok, cj, jk)), None
+
+    (lk, jk), _ = jax.lax.scan(
+        step, (jnp.zeros(shape, jnp.uint32), jnp.zeros(shape, jnp.int32)),
+        jnp.arange(32 + n_bits, dtype=jnp.int32))
+    return taken(lk, jk)
+
+
+def _accept_sorted(proposal, vw, sizes, cap, priority):
+    """Sort-based capped acceptance → (n,) bool (see `capped_accept`)."""
+    n = proposal.shape[0]
     # sort by (target, -priority): group per target, best first
     order = jnp.lexsort((-priority, proposal))
     t_s = proposal[order]
@@ -58,7 +130,31 @@ def capped_accept(labels: jax.Array, proposal: jax.Array, vwgt: jax.Array,
     base = jax.lax.cummax(base)
     inflow = cums - base                  # inclusive inflow within target run
     ok_s = sizes[t_s] + inflow <= cap[t_s]
-    ok = jnp.zeros((n,), bool).at[order].set(ok_s)
+    return jnp.zeros((n,), bool).at[order].set(ok_s)
+
+
+def capped_accept(labels: jax.Array, proposal: jax.Array, vwgt: jax.Array,
+                  sizes: jax.Array, cap: jax.Array,
+                  priority: jax.Array) -> jax.Array:
+    """Accept moves in priority order (desc) per target until capacity.
+
+    Per target, movers are taken in (priority desc, index asc) order while
+    ``size[t] + inclusive inflow <= cap[t]``.  Guarantee: for every target
+    t, size[t] + accepted_inflow[t] <= cap[t] (outflow ignored →
+    conservative).  Returns new labels.
+
+    One sort orders the movers; where sorts are slow to compile
+    (`sorting.sort_free`) the same prefix comes from `accept_prefix`.
+    """
+    n = labels.shape[0]
+    moving = proposal != labels
+    vw = jnp.where(moving, vwgt, 0.0)
+    if sort_free():
+        ok = accept_prefix(proposal.astype(jnp.int32), priority, vw,
+                           jnp.arange(n, dtype=jnp.int32), n.bit_length(),
+                           sizes, cap)
+    else:
+        ok = _accept_sorted(proposal, vw, sizes, cap, priority)
     return jnp.where(moving & ok, proposal, labels)
 
 
@@ -135,7 +231,8 @@ def _segment_affinity(g: CooGraph, labels: jax.Array, sizes: jax.Array,
     # the position-keyed tie-break noise below.  Padding edges land in
     # dead-only runs, which aff_eff masks to _NEG.
     dead = jnp.where(g.w > 0, 0, 1)
-    order = jnp.lexsort((tgt, g.src, dead))    # runs of equal (src, tgt)
+    nbits = bit_width(n - 1)
+    order = lexsort((tgt, g.src, dead), (nbits, nbits, 1))  # (src, tgt) runs
     src_e = g.src[order]
     lab_e = tgt[order]
     ws = g.w[order]
@@ -165,28 +262,37 @@ def _segment_affinity(g: CooGraph, labels: jax.Array, sizes: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("iters",))
-def _cluster_lp_jit(g: CooGraph, labels0: jax.Array, cap: jax.Array,
-                    key: jax.Array, iters: int):
+def _cluster_lp_round(g: CooGraph, labels: jax.Array, cap: jax.Array,
+                      key: jax.Array, i: jax.Array, moved: jax.Array,
+                      iters: int):
+    """Round ``i`` of ``iters`` clustering LP rounds → (new labels,
+    ``moved`` + the number of nodes it moved)."""
     n = g.n_pad
     vw = g.vwgt
+    sizes = jnp.zeros((n,), jnp.float32).at[labels].add(vw)
+    k1, _ = jax.random.split(jax.random.split(key, iters)[i])
+    best_lab, best_aff, own_aff = _segment_affinity(g, labels, sizes, cap, k1)
+    improve = (best_aff > own_aff + _GAIN_EPS) & (best_lab < n)
+    node_par = (jnp.arange(n) + i) % 2 == 0
+    want = improve & node_par
+    proposal = jnp.where(want, best_lab, labels).astype(labels.dtype)
+    pri = jnp.where(want, best_aff - own_aff, _NEG)
+    new_labels = capped_accept(labels, proposal, vw, sizes, cap, pri)
+    return new_labels, moved + jnp.sum((new_labels != labels)
+                                       .astype(jnp.int32))
 
-    def body(carry, key_r):
-        labels, parity = carry
-        sizes = jnp.zeros((n,), jnp.float32).at[labels].add(vw)
-        k1, _ = jax.random.split(key_r)
-        best_lab, best_aff, own_aff = _segment_affinity(g, labels, sizes,
-                                                        cap, k1)
-        improve = (best_aff > own_aff + _GAIN_EPS) & (best_lab < n)
-        node_par = (jnp.arange(n) + parity) % 2 == 0
-        want = improve & node_par
-        proposal = jnp.where(want, best_lab, labels).astype(labels.dtype)
-        pri = jnp.where(want, best_aff - own_aff, _NEG)
-        new_labels = capped_accept(labels, proposal, vw, sizes, cap, pri)
-        moved = jnp.sum((new_labels != labels).astype(jnp.int32))
-        return (new_labels, parity + 1), moved
 
-    keys = jax.random.split(key, iters)
-    (labels, _), moved = jax.lax.scan(body, (labels0, jnp.int32(0)), keys)
+def _cluster_lp(g: CooGraph, labels0: jax.Array, cap: jax.Array,
+                key: jax.Array, iters: int):
+    """``iters`` clustering rounds → (labels, total node moves).
+
+    The rounds are dispatched one compiled round at a time rather than as
+    a device loop: the TPU compiler takes several times longer to build
+    the round inside a loop than alone, and the dispatches are cheap."""
+    labels, moved = labels0, jnp.int32(0)
+    for i in range(iters):
+        labels, moved = _cluster_lp_round(g, labels, cap, key, jnp.int32(i),
+                                          moved, iters)
     return labels, moved
 
 
@@ -200,6 +306,6 @@ def size_constrained_lp(g: Graph, max_cluster_weight: float,
     # one-op program (iota / broadcast_in_dim) per shape
     labels0 = jnp.asarray(np.arange(n_pad, dtype=np.int32))
     cap = jnp.asarray(np.full(n_pad, max_cluster_weight, np.float32))
-    labels, _ = _cluster_lp_jit(coo, labels0, cap, jax.random.PRNGKey(seed),
-                                iters)
+    labels, _ = _cluster_lp(coo, labels0, cap, jax.random.PRNGKey(seed),
+                            iters)
     return np.asarray(labels)[:g.n]

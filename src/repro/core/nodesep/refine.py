@@ -34,7 +34,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.csr import Graph, CooGraph, EllGraph, to_coo, to_ell
+from repro.core.csr import (Graph, CooGraph, EllGraph, neighbour_any,
+                            to_coo, to_ell)
 
 SEP = 2                 # the separator label
 _NEG = -1e30
@@ -141,7 +142,7 @@ def _sep_refine_scan(g: CooGraph, labels0: jax.Array, cap: jax.Array,
         new_labels = capped_accept(labels, proposal, vw, sizes, cap3, pri)
         # two-hop pull-in: opposite-side neighbours of movers enter S
         moved = (new_labels != labels) & in_sep
-        reach = jnp.zeros((n,), bool).at[g.dst].max(moved[g.src] & (g.w > 0))
+        reach = neighbour_any(g, moved, ell)
         pulled = reach & (labels == other)
         new_labels = jnp.where(pulled, SEP, new_labels)
         new_sizes = sizes_of(new_labels)

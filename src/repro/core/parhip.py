@@ -24,9 +24,8 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro import obs
 from repro.core.csr import Graph, _pow2_pad

@@ -22,7 +22,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.csr import Graph, CooGraph, EllGraph, to_coo, to_ell
+from repro.core.csr import (Graph, CooGraph, EllGraph, neighbour_any,
+                            to_coo, to_ell)
 from repro.core.partition import edge_cut_device, edge_cut, is_feasible
 from repro.core import lp as lp_mod
 
@@ -82,8 +83,7 @@ def _refine_scan(g: CooGraph, labels0: jax.Array, cap: jax.Array,
         new_labels = jnp.where(live, prop_labels, labels)
         new_sizes = jnp.where(live, prop_sizes, sizes)
         moved = new_labels != labels
-        reach = jnp.zeros((n,), bool).at[g.dst].max(
-            moved[g.src] & (g.w > 0))
+        reach = neighbour_any(g, moved, ell)
         active = active | reach | moved
         cut = edge_cut_device(g, new_labels)
         feas = jnp.max(new_sizes - cap) <= 1e-6

@@ -13,10 +13,12 @@ padded slot may alias a real row when a dim lands exactly on its bucket.
 Affinity columns for capacity-zero padding blocks are computed but can
 never win a gain comparison, so k-bucketed calls share one tile program
 with the larger-k calls they pad up to.
+
+Every trace that places a compiled (not interpreted) kernel into a program
+counts ``kernels/mosaic/<kernel>`` in the ``repro.obs`` registry, so a run
+can show that its refinement programs really hold the TPU kernels.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ from repro.kernels import lp_affinity as _lpk
 from repro.kernels import pin_affinity as _pink
 from repro.kernels import ssd_scan as _ssdk
 from repro.kernels import ref as _ref
+from repro.obs import metrics
 
 
 #: Machine-readable form of the masking contract above, keyed by public op:
@@ -40,8 +43,35 @@ PADDING_CONTRACT = {
 }
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _interpret(kernel: str) -> bool:
+    """Interpret off TPU; on TPU count the compiled kernel's trace."""
+    if jax.default_backend() != "tpu":
+        return True
+    metrics.inc(f"kernels/mosaic/{kernel}")
+    return False
+
+
+@jax.custom_batching.custom_vmap
+def take(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table[idx]`` for a 1-D table, batching as one flat gather.
+
+    Under ``vmap`` over the table, the TPU compiler can lower the batched
+    gather with a temporary of ~512 bytes per index (8.6 GB for a
+    2 × 2^17 label table gathered by 2^24 pin slots, which left kahypar's
+    refinement unable to load on a 16 GB chip); offsetting the indices
+    into the flattened table keeps it a plain gather."""
+    return table[idx]
+
+
+@take.def_vmap
+def _take_vmap(axis_size, in_batched, table, idx):
+    table_b, idx_b = in_batched
+    if not table_b:
+        return take(table, idx), idx_b
+    n = table.shape[1]
+    rows = jnp.arange(axis_size, dtype=idx.dtype) * n
+    rows = rows.reshape((axis_size,) + (1,) * (idx.ndim - idx_b))
+    return take(table.reshape(-1), idx + rows), True
 
 
 def _round_up(x: int, m: int) -> int:
@@ -61,12 +91,13 @@ def lp_affinity(nbr: jax.Array, wgt: jax.Array, labels: jax.Array,
         return _ref.affinity_ref(nbr_lab, wgt, k)
     n_pad, dmax = nbr.shape
     k_pad = _round_up(k, _lpk.BK)
-    d_pad = _round_up(dmax, _lpk.DC)
+    d_pad = _lpk.padded_width(dmax)
     if d_pad != dmax:
         pad = d_pad - dmax
         nbr_lab = jnp.pad(nbr_lab, ((0, 0), (0, pad)), constant_values=0)
         wgt = jnp.pad(wgt, ((0, 0), (0, pad)))
-    aff = _lpk.affinity_pallas(nbr_lab, wgt, k_pad, interpret=_interpret())
+    aff = _lpk.affinity_pallas(nbr_lab, wgt, k_pad,
+                               interpret=_interpret("lp_affinity"))
     return aff[:, :k]
 
 
@@ -92,19 +123,20 @@ def pin_count(pins: jax.Array, pin_mask: jax.Array, netw: jax.Array,
     and net-weight scaling run in the Pallas kernel (compute-bound).  Padded
     pin slots carry pin_mask == 0 and contribute nothing.
     """
-    pin_lab = labels[pins]                        # XLA gather
+    pin_lab = take(labels, pins)                  # XLA gather
     if not use_pallas:
         cnt, score = _ref.pin_count_ref(pin_lab, pin_mask, netw, k)
         return cnt, score
     e_pad, pmax = pins.shape
     k_pad = _round_up(k, _lpk.BK)
-    p_pad = _round_up(pmax, _lpk.DC)
+    p_pad = _lpk.padded_width(pmax)
     if p_pad != pmax:
         pad = p_pad - pmax
         pin_lab = jnp.pad(pin_lab, ((0, 0), (0, pad)), constant_values=0)
         pin_mask = jnp.pad(pin_mask, ((0, 0), (0, pad)))
     cnt, score = _pink.pin_affinity_pallas(pin_lab, pin_mask, netw, k_pad,
-                                           interpret=_interpret())
+                                           interpret=_interpret(
+                                               "pin_affinity"))
     return cnt[:, :k], score[:, :k]
 
 
@@ -136,5 +168,5 @@ def ssd_scan(x: jax.Array, logdecay: jax.Array, b: jax.Array, c: jax.Array,
         b = jnp.pad(b, ((0, 0), (0, pad), (0, 0)))
         c = jnp.pad(c, ((0, 0), (0, pad), (0, 0)))
     y = _ssdk.ssd_scan_pallas(x, logdecay, b, c, chunk=chunk,
-                              interpret=_interpret())
+                              interpret=_interpret("ssd_scan"))
     return y[:, :l]

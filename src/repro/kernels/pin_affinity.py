@@ -11,13 +11,14 @@ lab[u] = b}|`` is then one XLA gather+sum of ``score`` rows over the
 vertex→nets ELL (kernels/ops.py) — irregular gathers stay outside the
 kernel exactly as in lp_affinity.py.
 
-Same design as lp_affinity (128-row tiles, one-hot contraction on the VPU,
-dmax walked in chunks of DC); the differences are the per-row net-weight
-scaling fused into the kernel and the dual (cnt, score) output, which the
-refinement gain formulas both need (λ−1 gains want raw counts, absorption
-affinities want weighted scores).
+Same tiling and chunk walk as lp_affinity (its ``accumulate_chunk`` builds
+the count tile); the differences are the per-row net-weight scaling fused
+into the last chunk and the dual (cnt, score) output, which the refinement
+gain formulas both need (λ−1 gains want raw counts, absorption affinities
+want weighted scores).
 
-Grid: (e_pad/BN, k_pad/BK); net weights ride along as a (BN, 1) column.
+Grid: (e_pad/BN, k_pad/BK, pmax/DCH); net weights ride along as a (BN, 1)
+column.
 """
 from __future__ import annotations
 
@@ -27,32 +28,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.lp_affinity import BN, BK, DC
+from repro.kernels.lp_affinity import (BK, BN, COMPILER_PARAMS,
+                                       accumulate_chunk, grid_and_chunk)
 
 
-def _pin_affinity_kernel(pin_lab_ref, mask_ref, netw_ref, cnt_ref, score_ref):
-    """One (BN nets × BK blocks) tile of (cnt, score)."""
-    j = pl.program_id(1)
-    lab = pin_lab_ref[...]          # (BN, pmax) int32
-    mask = mask_ref[...]            # (BN, pmax) f32
-    netw = netw_ref[...]            # (BN, 1) f32
-    pmax = lab.shape[1]
-    base = j * BK
-    kids = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, BK), 2)
+def _pin_affinity_kernel(pin_lab_ref, mask_ref, netw_ref, cnt_ref,
+                         score_ref):
+    """One chunk of one (BN nets × BK blocks) tile of (cnt, score)."""
+    accumulate_chunk(pin_lab_ref, mask_ref, cnt_ref)
 
-    # strong-typed counter scan (fori_loop would seed a weak-int32 carry
-    # from its python bounds — the repro.analysis hygiene contract)
-    def step(carry, _):
-        d, acc = carry
-        lab_c = jax.lax.dynamic_slice(lab, (0, d * DC), (BN, DC))
-        msk_c = jax.lax.dynamic_slice(mask, (0, d * DC), (BN, DC))
-        hit = (lab_c[:, :, None] == kids).astype(jnp.float32)  # (BN, DC, BK)
-        return (d + 1, acc + jnp.sum(hit * msk_c[:, :, None], axis=1)), None
-
-    carry0 = (jnp.int32(0), jnp.zeros((BN, BK), jnp.float32))
-    (_, cnt), _ = jax.lax.scan(step, carry0, None, length=pmax // DC)
-    cnt_ref[...] = cnt
-    score_ref[...] = cnt * netw
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _():
+        score_ref[...] = cnt_ref[...] * netw_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("k_pad", "interpret"))
@@ -62,27 +49,23 @@ def pin_affinity_pallas(pin_lab: jax.Array, mask: jax.Array,
     """(e_pad, pmax) pin labels/mask + (e_pad,) net weights →
     ((e_pad, k_pad) counts, (e_pad, k_pad) weighted scores).
 
-    Requires e_pad % BN == 0, k_pad % BK == 0, pmax % DC == 0.
+    Requires e_pad % BN == 0, k_pad % BK == 0 and pmax == padded_width(pmax).
     """
     e_pad, pmax = pin_lab.shape
-    assert e_pad % BN == 0 and k_pad % BK == 0 and pmax % DC == 0, (
-        e_pad, k_pad, pmax)
-    grid = (e_pad // BN, k_pad // BK)
+    grid, dch = grid_and_chunk(e_pad, k_pad, pmax)
+    in_spec = pl.BlockSpec((BN, dch), lambda i, j, c: (i, c))
+    out_spec = pl.BlockSpec((BN, BK), lambda i, j, c: (i, j))
     return pl.pallas_call(
         _pin_affinity_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((BN, pmax), lambda i, j: (i, 0)),
-            pl.BlockSpec((BN, pmax), lambda i, j: (i, 0)),
-            pl.BlockSpec((BN, 1), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((BN, BK), lambda i, j: (i, j)),
-            pl.BlockSpec((BN, BK), lambda i, j: (i, j)),
-        ],
+        in_specs=[in_spec, in_spec,
+                  pl.BlockSpec((BN, 1), lambda i, j, c: (i, 0))],
+        out_specs=[out_spec, out_spec],
         out_shape=[
             jax.ShapeDtypeStruct((e_pad, k_pad), jnp.float32),
             jax.ShapeDtypeStruct((e_pad, k_pad), jnp.float32),
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="pin_affinity",
     )(pin_lab, mask, netw.reshape(e_pad, 1))

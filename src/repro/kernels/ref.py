@@ -4,11 +4,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+#: full-f32 contraction: the TPU's default matmul precision rounds f32
+#: operands to bf16, which would make weights above 256 inexact
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def affinity_ref(nbr_lab: jax.Array, wgt: jax.Array, k_pad: int) -> jax.Array:
     """aff[v, b] = Σ_j wgt[v, j] · [nbr_lab[v, j] == b]  — (n_pad, k_pad)."""
     hit = jax.nn.one_hot(nbr_lab, k_pad, dtype=jnp.float32)   # (n, d, k)
-    return jnp.einsum("nd,ndk->nk", wgt.astype(jnp.float32), hit)
+    return jnp.einsum("nd,ndk->nk", wgt.astype(jnp.float32), hit,
+                      precision=_EXACT)
 
 
 def pin_count_ref(pin_lab: jax.Array, mask: jax.Array, netw: jax.Array,
@@ -20,7 +25,8 @@ def pin_count_ref(pin_lab: jax.Array, mask: jax.Array, netw: jax.Array,
     match the Pallas kernel bit-for-bit (for integer-valued net weights).
     """
     hit = jax.nn.one_hot(pin_lab, k_pad, dtype=jnp.float32)   # (e, p, k)
-    cnt = jnp.einsum("ep,epk->ek", mask.astype(jnp.float32), hit)
+    cnt = jnp.einsum("ep,epk->ek", mask.astype(jnp.float32), hit,
+                     precision=_EXACT)
     return cnt, cnt * netw[:, None]
 
 

@@ -163,7 +163,7 @@ def moe_ffn_a2a(params, x, cfg):
     would serialise the all-to-all.
     """
     from repro.models import shardings as SH
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = SH.current_mesh()
     if mesh is None:

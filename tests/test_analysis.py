@@ -17,6 +17,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs
@@ -27,7 +28,6 @@ from repro.analysis import checkers, lint, padding as padmod, spmd, tracing
 from repro.analysis.findings import Finding
 from repro.analysis.registry import (DRIVER_ENTRIES, EntryPoint, PaddingSpec,
                                      _perturb_coo, _ring_graph)
-from repro.compat import shard_map
 
 
 def _codes(findings):
@@ -368,8 +368,8 @@ def test_pin_cluster_lp_noise_still_tiebreaks():
     coo = to_coo(g)
     labs = np.arange(coo.n_pad, dtype=np.int32)
     cap = np.full(coo.n_pad, 6.0 * g.n, np.float32)
-    out1, _ = L._cluster_lp_jit(coo, jnp.asarray(labs), jnp.asarray(cap),
-                                jax.random.PRNGKey(0), 4)
+    out1, _ = L._cluster_lp(coo, jnp.asarray(labs), jnp.asarray(cap),
+                            jax.random.PRNGKey(0), 4)
     out1 = np.asarray(out1)[:g.n]
     # every vertex joined a cluster led by a real vertex
     assert out1.min() >= 0 and out1.max() < coo.n_pad

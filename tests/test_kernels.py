@@ -8,7 +8,8 @@ from repro.kernels import ops, ref
 
 
 @pytest.mark.parametrize("n_pad,dmax,k", [
-    (128, 8, 2), (256, 24, 5), (128, 16, 130), (384, 40, 17)])
+    (128, 8, 2), (256, 24, 5), (128, 16, 130), (384, 40, 17),
+    (128, 300, 7)])  # dmax > DCH → several accumulated column chunks
 @pytest.mark.parametrize("dtype", [np.float32])
 def test_lp_affinity_sweep(n_pad, dmax, k, dtype):
     rng = np.random.default_rng(n_pad + dmax + k)
@@ -22,6 +23,24 @@ def test_lp_affinity_sweep(n_pad, dmax, k, dtype):
                             jnp.asarray(wgt), k)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("e_pad,pmax,k", [(128, 8, 3), (256, 260, 9)])
+def test_pin_count_chunks_bit_exact(e_pad, pmax, k):
+    """Integer net weights: counts and scores equal the oracle bit for bit,
+    also when the pin walk spans several grid chunks."""
+    rng = np.random.default_rng(e_pad + pmax)
+    pins = rng.integers(0, e_pad, (e_pad, pmax)).astype(np.int32)
+    mask = (rng.random((e_pad, pmax)) > 0.4).astype(np.float32)
+    netw = rng.integers(0, 1000, (e_pad,)).astype(np.float32)
+    labels = jnp.asarray(rng.integers(0, k, (e_pad,)).astype(np.int32))
+    cnt, score = ops.pin_count(jnp.asarray(pins), jnp.asarray(mask),
+                               jnp.asarray(netw), labels, k)
+    cnt_r, score_r = ref.pin_count_ref(labels[jnp.asarray(pins)],
+                                       jnp.asarray(mask), jnp.asarray(netw),
+                                       k)
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_r))
+    np.testing.assert_array_equal(np.asarray(score), np.asarray(score_r))
 
 
 @pytest.mark.parametrize("bh,l,p,n,chunk", [
@@ -86,3 +105,22 @@ def test_online_attention_matches_dense():
                             is_causal=True)
     np.testing.assert_allclose(np.asarray(dense_w), np.asarray(online_w),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_take_batches_as_flat_gather():
+    """ops.take under vmap (batched table, shared or batched indices,
+    nested) equals plain indexing."""
+    rng = np.random.default_rng(5)
+    t = jnp.asarray(rng.integers(0, 100, (3, 4, 50)).astype(np.int32))
+    i = jnp.asarray(rng.integers(0, 50, (4, 7, 5)).astype(np.int32))
+    np.testing.assert_array_equal(ops.take(t[0, 0], i[0]), t[0, 0][i[0]])
+    np.testing.assert_array_equal(jax.vmap(ops.take, (0, None))(t[0], i[0]),
+                                  t[0][:, i[0]])
+    np.testing.assert_array_equal(
+        jax.jit(jax.vmap(ops.take))(t[0], i),
+        jnp.stack([t[0, j][i[j]] for j in range(4)]))
+    np.testing.assert_array_equal(jax.vmap(ops.take, (None, 0))(t[0, 0], i),
+                                  t[0, 0][i])
+    np.testing.assert_array_equal(
+        jax.vmap(jax.vmap(ops.take, (0, None)), (0, None))(t, i[0]),
+        t[:, :, i[0]])
