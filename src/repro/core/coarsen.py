@@ -86,23 +86,27 @@ def heavy_edge_matching(g: Graph, seed: int = 0, rounds: int = 3,
 
 def lp_clustering(g: Graph, max_cluster_weight: float, iters: int = 8,
                   seed: int = 0,
-                  forbidden: Optional[np.ndarray] = None) -> np.ndarray:
+                  forbidden: Optional[np.ndarray] = None,
+                  recorder=None) -> np.ndarray:
     """Size-constrained LP clustering (social coarsening, §2.4).
 
     ``forbidden`` directed-edge mask: those edges' weights are zeroed for the
     clustering and any residual violation is split apart afterwards, so no
-    forbidden edge is ever contracted.
+    forbidden edge is ever contracted.  ``recorder`` gets the rounds'
+    ``coarsen/lp_*`` counters (`lp.size_constrained_lp`).
     """
     if forbidden is None:
         clusters = lp_mod.size_constrained_lp(g, max_cluster_weight,
-                                              iters=iters, seed=seed)
+                                              iters=iters, seed=seed,
+                                              recorder=recorder)
     else:
         g2 = Graph(g.xadj, g.adjncy, g.vwgt,
                    np.where(forbidden, 0, g.adjwgt).astype(np.int64))
         # w=0 edges contribute nothing; the LP may still merge endpoints via
         # other paths — split violators below.
         clusters = lp_mod.size_constrained_lp(g2, max_cluster_weight,
-                                              iters=iters, seed=seed)
+                                              iters=iters, seed=seed,
+                                              recorder=recorder)
         src = g.edge_sources()
         bad = forbidden & (clusters[src] == clusters[g.adjncy])
         viol = np.unique(src[bad])

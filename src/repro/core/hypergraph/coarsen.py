@@ -76,13 +76,14 @@ def star_expansion(hg: Hypergraph) -> Graph:
 def lp_clustering(hg: Hypergraph, max_cluster_weight: float,
                   iters: int = 8, seed: int = 0,
                   max_net_size: int = 64,
-                  protect=None) -> np.ndarray:
+                  protect=None, recorder=None) -> np.ndarray:
     """Size-constrained LP clustering on the clique-expansion rating.
 
     ``protect`` is an optional sequence of partitions whose cuts must not
     be contracted (V-cycle / combine re-coarsening): rating edges crossing
     any protected cut are zeroed so the LP avoids them; the engine's
-    signature split removes any residual violation.
+    signature split removes any residual violation.  ``recorder`` gets the
+    rounds' ``coarsen/lp_*`` counters (`lp.size_constrained_lp`).
     """
     g = clique_expansion(hg, max_net_size=max_net_size)
     if len(g.adjncy) == 0:
@@ -93,7 +94,7 @@ def lp_clustering(hg: Hypergraph, max_cluster_weight: float,
         g = Graph(g.xadj, g.adjncy, g.vwgt,
                   np.where(cross, 0, g.adjwgt).astype(np.int64))
     return lp_mod.size_constrained_lp(g, max_cluster_weight, iters=iters,
-                                      seed=seed)
+                                      seed=seed, recorder=recorder)
 
 
 def contract(hg: Hypergraph, clusters: np.ndarray):

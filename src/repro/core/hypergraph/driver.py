@@ -89,7 +89,8 @@ class HypergraphMedium(ML.ViewCache):
         return C.lp_clustering(self.hg, max_cluster_weight,
                                iters=self.cfg.lp_iters, seed=seed,
                                max_net_size=self.cfg.max_net_size,
-                               protect=protect)
+                               protect=protect,
+                               recorder=ML.recorder_of(self))
 
     def contract(self, clusters: np.ndarray):
         coarse, cl = C.contract(self.hg, clusters)
@@ -106,6 +107,7 @@ class HypergraphMedium(ML.ViewCache):
     def refine(self, part: np.ndarray, k: int, eps: float, seed: int,
                force_balance: Optional[bool] = None) -> np.ndarray:
         hc, ell = self.views
+        rec = ML.recorder_of(self)
         if force_balance is None:
             force_balance = not M.is_feasible(self.hg, part, k, eps)
         out = refine_hypergraph(self.hg, part, k, eps,
@@ -113,14 +115,10 @@ class HypergraphMedium(ML.ViewCache):
                                 objective=self.obj,
                                 force_balance=force_balance,
                                 use_kernel=self.use_kernel, hc=hc, ell=ell,
-                                batch_floor=self.cfg.batch_floor)
-        rec = ML.recorder_of(self)
-        if rec.enabled:
-            rec.count("refine/rounds", self.cfg.refine_rounds)
-            rec.count("refine/moves",
-                      int(np.sum(out != np.asarray(part, dtype=np.int64))))
-            if force_balance:
-                rec.count("refine/forced_balance")
+                                batch_floor=self.cfg.batch_floor,
+                                recorder=rec)
+        if force_balance:
+            rec.count("refine/forced_balance")
         return out
 
     def refine_batch(self, parts: Sequence[np.ndarray], k: int, eps: float,
@@ -131,7 +129,8 @@ class HypergraphMedium(ML.ViewCache):
                                        seed=seed, objective=self.obj,
                                        use_kernel=self.use_kernel,
                                        hc=hc, ell=ell, keys=keys,
-                                       batch_floor=self.cfg.batch_floor)
+                                       batch_floor=self.cfg.batch_floor,
+                                       recorder=ML.recorder_of(self))
 
     def polish(self, part: np.ndarray, k: int, eps: float,
                seed: int) -> np.ndarray:

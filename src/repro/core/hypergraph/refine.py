@@ -26,6 +26,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import lp as lp_mod
 from repro.core.hypergraph import metrics as M
 from repro.core.hypergraph.container import (EllHypergraph, Hypergraph,
@@ -43,7 +44,10 @@ def _hyper_refine_scan(hc: PinCoo, labels0: jax.Array, cap: jax.Array,
                        ell: Optional[EllHypergraph] = None):
     """One candidate's scan (unjitted; vmapped by `_hyper_refine_scan_batch`
     — single refines ride the batched program at the medium's batch floor,
-    DESIGN.md §12)."""
+    DESIGN.md §12) → (labels, objective, moves): ``moves`` (rounds,)
+    counts the vertices each round moved.  The round's steps carry the
+    named scopes of the graph scan: ``affinity`` (the pin counts), ``cut``,
+    ``gain``, ``accept`` and ``sizes``."""
     n = hc.n_pad
     vw = hc.vwgt
     w_pin = hc.mask * hc.netw[hc.pe]                      # (p_pad,)
@@ -82,40 +86,47 @@ def _hyper_refine_scan(hc: PinCoo, labels0: jax.Array, cap: jax.Array,
 
     def body(carry, key_r):
         labels, sizes, best_obj, best_labels, parity = carry
-        cnt = cnt_fn(labels)
+        with jax.named_scope("affinity"):
+            cnt = cnt_fn(labels)
         # track best feasible state seen (undo-to-best)
-        obj = obj_fn(cnt, hc.netw)
-        feas = jnp.max(sizes - cap) <= 1e-6
-        better = feas & (obj < best_obj)
-        best_obj = jnp.where(better, obj, best_obj)
-        best_labels = jnp.where(better, labels, best_labels)
+        with jax.named_scope("cut"):
+            obj = obj_fn(cnt, hc.netw)
+            feas = jnp.max(sizes - cap) <= 1e-6
+            better = feas & (obj < best_obj)
+            best_obj = jnp.where(better, obj, best_obj)
+            best_labels = jnp.where(better, labels, best_labels)
         # propose + accept moves
-        gain = gains(labels, cnt)
-        gain = gain + jax.random.uniform(key_r, (n, k), jnp.float32,
-                                         0.0, _NOISE)
-        gain = gain.at[jnp.arange(n), labels].set(_NEG)
-        room = sizes[None, :] + vw[:, None] <= cap[None, :]
-        gain = jnp.where(room, gain, _NEG)
-        best_gain = jnp.max(gain, axis=1)
-        best_tgt = jnp.argmax(gain, axis=1).astype(labels.dtype)
-        want = best_gain > _GAIN_EPS
-        # overweight blocks push nodes out regardless of gain (when forced)
-        over = sizes[labels] > cap[labels]
-        want = want | (jnp.asarray(force_balance)
-                       & over & (best_gain > _NEG / 2) & (vw > 0))
-        node_par = (jnp.arange(n) + parity) % 2 == 0
-        want = want & node_par
-        proposal = jnp.where(want, best_tgt, labels)
-        new_labels = lp_mod.capped_accept(labels, proposal, vw, sizes, cap,
-                                          jnp.where(want, best_gain, _NEG))
-        new_sizes = jnp.zeros((k,), jnp.float32).at[new_labels].add(vw)
+        with jax.named_scope("gain"):
+            gain = gains(labels, cnt)
+            gain = gain + jax.random.uniform(key_r, (n, k), jnp.float32,
+                                             0.0, _NOISE)
+            gain = gain.at[jnp.arange(n), labels].set(_NEG)
+            room = sizes[None, :] + vw[:, None] <= cap[None, :]
+            gain = jnp.where(room, gain, _NEG)
+            best_gain = jnp.max(gain, axis=1)
+            best_tgt = jnp.argmax(gain, axis=1).astype(labels.dtype)
+            want = best_gain > _GAIN_EPS
+            # overweight blocks push nodes out regardless of gain (forced)
+            over = sizes[labels] > cap[labels]
+            want = want | (jnp.asarray(force_balance)
+                           & over & (best_gain > _NEG / 2) & (vw > 0))
+            node_par = (jnp.arange(n) + parity) % 2 == 0
+            want = want & node_par
+            proposal = jnp.where(want, best_tgt, labels)
+        with jax.named_scope("accept"):
+            new_labels = lp_mod.capped_accept(
+                labels, proposal, vw, sizes, cap,
+                jnp.where(want, best_gain, _NEG))
+        with jax.named_scope("sizes"):
+            new_sizes = jnp.zeros((k,), jnp.float32).at[new_labels].add(vw)
         return (new_labels, new_sizes, best_obj, best_labels,
-                parity + 1), obj
+                parity + 1), jnp.sum((new_labels != labels)
+                                     .astype(jnp.int32))
 
     sizes0 = jnp.zeros((k,), jnp.float32).at[labels0].add(vw)
     keys = jax.random.split(key, rounds)
     carry0 = (labels0, sizes0, jnp.float32(jnp.inf), labels0, jnp.int32(0))
-    (labels, sizes, best_obj, best_labels, _), _ = jax.lax.scan(
+    (labels, sizes, best_obj, best_labels, _), moves = jax.lax.scan(
         body, carry0, keys)
     # evaluate the final state too
     obj = obj_fn(cnt_fn(labels), hc.netw)
@@ -124,7 +135,7 @@ def _hyper_refine_scan(hc: PinCoo, labels0: jax.Array, cap: jax.Array,
     best_obj = jnp.where(better, obj, best_obj)
     best_labels = jnp.where(better, labels, best_labels)
     have = jnp.isfinite(best_obj)
-    return jnp.where(have, best_labels, labels), best_obj
+    return jnp.where(have, best_labels, labels), best_obj, moves
 
 
 def _caps_for(hg: Hypergraph, k: int, eps: float) -> np.ndarray:
@@ -153,7 +164,8 @@ def _hyper_refine_scan_batch(hc: PinCoo, labels0: jax.Array, cap: jax.Array,
                              rounds: int, objective: str,
                              use_kernel: bool,
                              ell: Optional[EllHypergraph] = None):
-    """THE hypergraph refinement program: everything routes through here."""
+    """THE hypergraph refinement program: everything routes through here.
+    → (labels, objectives, moves), one row each per candidate."""
     def one(lab0, key, f):
         return _hyper_refine_scan(hc, lab0, cap, key, k, rounds, objective,
                                   f, use_kernel, ell=ell)
@@ -161,7 +173,11 @@ def _hyper_refine_scan_batch(hc: PinCoo, labels0: jax.Array, cap: jax.Array,
 
 
 def _run_hyper_scan_batch(hc, cap_np, labs, keys, force, k, rounds,
-                          objective, use_kernel, ell, batch_floor):
+                          objective, use_kernel, ell, batch_floor,
+                          recorder=None):
+    """Pad the batch to its bucket and run the one program; an enabled
+    ``recorder`` (default: the ambient one) gets its ``refine/*`` round
+    counters (`lp.count_round_moves`), read back after the labels."""
     from repro.core import multilevel as ML
     from repro.core.refine import _pad_rows, batch_bucket
     b = labs.shape[0]
@@ -170,13 +186,17 @@ def _run_hyper_scan_batch(hc, cap_np, labs, keys, force, k, rounds,
     ML.note_bucket_pad(b_pad - b)
     ML.note_program("hyper", hc.n_pad, hc.e_pad, hc.p_pad, k_pad, rounds,
                     objective, b_pad, use_kernel)
-    outs, _ = _hyper_refine_scan_batch(
+    outs, _, moves = _hyper_refine_scan_batch(
         hc, jnp.asarray(_pad_rows(labs, b_pad)),
         jnp.asarray(_pad_caps(np.asarray(cap_np), k_pad)),
         jnp.asarray(_pad_rows(keys, b_pad)),
         jnp.asarray(_pad_rows(force, b_pad)),
         k_pad, rounds, objective, use_kernel, ell=ell)
-    return np.asarray(outs, dtype=np.int64)[:b]
+    outs = np.asarray(outs, dtype=np.int64)[:b]
+    rec = recorder if recorder is not None else obs.current()
+    if rec.enabled:
+        lp_mod.count_round_moves(rec, "refine/", moves, b)
+    return outs
 
 
 def refine_hypergraph(hg: Hypergraph, part: np.ndarray, k: int,
@@ -186,13 +206,15 @@ def refine_hypergraph(hg: Hypergraph, part: np.ndarray, k: int,
                       use_kernel: Optional[bool] = None,
                       hc: Optional[PinCoo] = None,
                       ell: Optional[EllHypergraph] = None,
-                      batch_floor: int = 1) -> np.ndarray:
+                      batch_floor: int = 1,
+                      recorder=None) -> np.ndarray:
     """Polish ``part``; never returns a worse feasible objective.
 
     ``use_kernel=None`` resolves to the backend default (Pallas pin counts
     on TPU, COO scatter elsewhere); ``hc``/``ell`` accept cached views.
     ``batch_floor`` pads the batch dim up to the medium's bucket so this
-    single call reuses the tournament's compiled program.
+    single call reuses the tournament's compiled program.  ``recorder``
+    gets the round counters (`_run_hyper_scan_batch`).
     """
     if k <= 1 or hg.n == 0:
         return np.asarray(part, dtype=np.int64)
@@ -206,7 +228,8 @@ def refine_hypergraph(hg: Hypergraph, part: np.ndarray, k: int,
     keys = np.asarray(jax.random.PRNGKey(seed))[None]
     outs = _run_hyper_scan_batch(hc, _caps_for(hg, k, eps), labs, keys,
                                  np.asarray([force_balance]), k, rounds,
-                                 objective, use_kernel, ell, batch_floor)
+                                 objective, use_kernel, ell, batch_floor,
+                                 recorder)
     out = outs[0][:hg.n]
     score = M.connectivity if objective == "km1" else M.cut_net
     # paranoia: keep the better of (in, out) among feasible options
@@ -222,7 +245,8 @@ def refine_hypergraph_batch(hg: Hypergraph, parts: list, k: int,
                             hc: Optional[PinCoo] = None,
                             ell: Optional[EllHypergraph] = None,
                             keys: Optional[np.ndarray] = None,
-                            batch_floor: int = 1) -> list:
+                            batch_floor: int = 1,
+                            recorder=None) -> list:
     """Refine several candidate partitions in one vmapped device call (the
     initial-partition tournament shares a single compile).  ``keys``
     overrides the per-candidate PRNG keys (shape ``(b, 2)``) — the memetic
@@ -244,7 +268,8 @@ def refine_hypergraph_batch(hg: Hypergraph, parts: list, k: int,
                                            len(parts)))
     outs = _run_hyper_scan_batch(hc, _caps_for(hg, k, eps), labs,
                                  np.asarray(keys), force, k, rounds,
-                                 objective, use_kernel, ell, batch_floor)
+                                 objective, use_kernel, ell, batch_floor,
+                                 recorder)
     outs = outs[:, :hg.n]
     score = M.connectivity if objective == "km1" else M.cut_net
     result = []

@@ -112,7 +112,8 @@ class GraphMedium(ML.ViewCache):
         if self.cfg.coarsening == "lp":
             return C.lp_clustering(g, max_cluster_weight,
                                    iters=self.cfg.lp_iters, seed=seed,
-                                   forbidden=forbidden)
+                                   forbidden=forbidden,
+                                   recorder=ML.recorder_of(self))
         return C.heavy_edge_matching(g, seed=seed,
                                      max_cluster_weight=max_cluster_weight,
                                      forbidden=forbidden)
@@ -132,20 +133,16 @@ class GraphMedium(ML.ViewCache):
                force_balance: Optional[bool] = None) -> np.ndarray:
         g, cfg = self.g, self.cfg
         coo, ell = self.views
+        rec = ML.recorder_of(self)
         if force_balance is None:
             force_balance = not is_feasible(g, part, k, eps)
         out = R.refine_kway(g, part, k, eps, rounds=cfg.refine_rounds,
                             seed=seed, coo=coo, ell=ell,
                             use_kernel=self.use_kernel,
                             force_balance=force_balance,
-                            batch_floor=cfg.batch_floor)
-        rec = ML.recorder_of(self)
-        if rec.enabled:
-            rec.count("refine/rounds", cfg.refine_rounds)
-            rec.count("refine/moves",
-                      int(np.sum(out != np.asarray(part, dtype=np.int64))))
-            if force_balance:
-                rec.count("refine/forced_balance")
+                            batch_floor=cfg.batch_floor, recorder=rec)
+        if force_balance:
+            rec.count("refine/forced_balance")
         return self.polish(out, k, eps, seed)
 
     def refine_batch(self, parts: Sequence[np.ndarray], k: int, eps: float,
@@ -155,7 +152,8 @@ class GraphMedium(ML.ViewCache):
                                    rounds=self.cfg.refine_rounds, seed=seed,
                                    coo=coo, ell=ell,
                                    use_kernel=self.use_kernel, keys=keys,
-                                   batch_floor=self.cfg.batch_floor)
+                                   batch_floor=self.cfg.batch_floor,
+                                   recorder=ML.recorder_of(self))
 
     def polish(self, part: np.ndarray, k: int, eps: float,
                seed: int) -> np.ndarray:
@@ -166,7 +164,8 @@ class GraphMedium(ML.ViewCache):
                                       rounds=max(4, cfg.refine_rounds // 2),
                                       seed=seed, coo=coo,
                                       batch_floor=cfg.batch_floor,
-                                      rounds_bucket=cfg.refine_rounds)
+                                      rounds_bucket=cfg.refine_rounds,
+                                      recorder=ML.recorder_of(self))
         if cfg.use_flow and g.n <= cfg.flow_max_n and k <= 16:
             part = R.flow_refine_all_pairs(g, part, k, eps, seed=seed)
         return part
@@ -175,12 +174,13 @@ class GraphMedium(ML.ViewCache):
     def initial_candidates(self, k: int, eps: float,
                            seed: int) -> List[np.ndarray]:
         g, cfg = self.g, self.cfg
+        rec = ML.recorder_of(self)
 
         def refine2(sub: Graph, two: np.ndarray, frac0: float) -> np.ndarray:
             fr = np.asarray([frac0, 1.0 - frac0])
             return R.refine_kway(sub, two, 2, eps, rounds=cfg.refine_rounds,
                                  seed=seed, fractions=fr,
-                                 batch_floor=cfg.batch_floor)
+                                 batch_floor=cfg.batch_floor, recorder=rec)
 
         fn = refine2 if g.n <= 20000 else None
         return [I.recursive_bisection(g, k, seed=seed + 101 * t, refine_fn=fn)
@@ -230,5 +230,5 @@ def kaffpa(g: Graph, k: int, eps: float = 0.03, preset: str = "eco",
                   input_partition=input_partition)
     if enforce_balance and not is_feasible(g, best, k, eps):
         best = R.refine_kway(g, best, k, eps, rounds=30, seed=seed,
-                             force_balance=True)
+                             force_balance=True, recorder=report)
     return best

@@ -16,6 +16,12 @@ Two regimes:
 All functions operate on pow2-padded arrays (see csr.CooGraph docstring), so
 jit caches hit across multilevel levels.  Padding rows have zero vertex and
 edge weight and never affect sizes, cuts, or gains.
+
+The round programs name their steps with ``jax.named_scope`` (``affinity``,
+``gain``, ``accept``, ``sizes``; ``rating`` in clustering), so a profiler
+trace puts each device op down to one, and count on the device the
+vertices each round moved; `count_round_moves` reads the counts back only
+for an enabled recorder.
 """
 from __future__ import annotations
 
@@ -26,12 +32,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.csr import CooGraph, Graph, to_coo
 from repro.core.sorting import bit_width, lexsort, sort_free
 
 _NEG = -1e30
 _NOISE = 1e-4          # random tie-break amplitude
 _GAIN_EPS = 1e-3       # strictly-positive-gain threshold (> noise)
+
+
+def count_round_moves(rec, prefix: str, moves, real_rows: int) -> None:
+    """Emit a round program's device-counted moves to ``rec`` (one transfer):
+    ``<prefix>rounds``, every row-round the program ran, padding rows and
+    masked rounds included; ``<prefix>rounds_moved``, the rounds of the
+    first ``real_rows`` rows that moved a vertex; ``<prefix>moves``, the
+    vertices those rounds moved.  ``moves`` is ``(rounds,)`` or ``(rows,
+    rounds)``; a masked round moves nothing, so it never counts as moved."""
+    m = np.asarray(moves)
+    m = m.reshape(-1, m.shape[-1])
+    real = m[:real_rows]
+    rec.count(prefix + "rounds", m.size)
+    rec.count(prefix + "rounds_moved", int(np.count_nonzero(real)))
+    rec.count(prefix + "moves", int(real.sum()))
 
 
 # ---------------------------------------------------------------------------
@@ -179,35 +201,41 @@ def kway_lp_round(g: CooGraph, labels: jax.Array, sizes: jax.Array,
     batched tournament vmaps over it — candidates differ in feasibility).
     """
     n = g.n_pad
-    aff = (affinity_fn or kway_affinity_coo)(g, labels, k)
-    noise = jax.random.uniform(key, (n, k), jnp.float32, 0.0, _NOISE)
-    own = jnp.take_along_axis(aff, labels[:, None].astype(jnp.int32), axis=1)[:, 0]
-    gain = aff - own[:, None] + noise
-    # own block is not a move target
-    gain = gain.at[jnp.arange(n), labels].set(_NEG)
-    # full targets are not candidates
     vw = g.vwgt
-    room = sizes[None, :] + vw[:, None] <= cap[None, :]
-    gain = jnp.where(room, gain, _NEG)
-    best_gain = jnp.max(gain, axis=1)
-    best_tgt = jnp.argmax(gain, axis=1).astype(labels.dtype)
-    # traced flag (like force_balance): zero-gain admission rides the batch
-    # dim instead of forking the compiled program per variant
-    thresh = jnp.where(jnp.asarray(allow_zero_gain), -_GAIN_EPS, _GAIN_EPS)
-    want = best_gain > thresh
-    # overweight blocks push nodes out regardless of gain (when forced)
-    over = sizes[labels] > cap[labels]
-    want = want | (jnp.asarray(force_balance)
-                   & over & (best_gain > _NEG / 2) & (vw > 0))
-    # parity tie-break (avoid A<->B swap oscillation)
-    node_par = (jnp.arange(n) + parity) % 2 == 0
-    want = want & node_par
-    if active is not None:
-        want = want & active
-    proposal = jnp.where(want, best_tgt, labels)
-    new_labels = capped_accept(labels, proposal, vw, sizes, cap,
-                               jnp.where(want, best_gain, _NEG))
-    new_sizes = jnp.zeros((k,), sizes.dtype).at[new_labels].add(vw)
+    with jax.named_scope("affinity"):
+        aff = (affinity_fn or kway_affinity_coo)(g, labels, k)
+    with jax.named_scope("gain"):
+        noise = jax.random.uniform(key, (n, k), jnp.float32, 0.0, _NOISE)
+        own = jnp.take_along_axis(aff, labels[:, None].astype(jnp.int32),
+                                  axis=1)[:, 0]
+        gain = aff - own[:, None] + noise
+        # own block is not a move target
+        gain = gain.at[jnp.arange(n), labels].set(_NEG)
+        # full targets are not candidates
+        room = sizes[None, :] + vw[:, None] <= cap[None, :]
+        gain = jnp.where(room, gain, _NEG)
+        best_gain = jnp.max(gain, axis=1)
+        best_tgt = jnp.argmax(gain, axis=1).astype(labels.dtype)
+        # traced flag (like force_balance): zero-gain admission rides the
+        # batch dim instead of forking the compiled program per variant
+        thresh = jnp.where(jnp.asarray(allow_zero_gain), -_GAIN_EPS,
+                           _GAIN_EPS)
+        want = best_gain > thresh
+        # overweight blocks push nodes out regardless of gain (when forced)
+        over = sizes[labels] > cap[labels]
+        want = want | (jnp.asarray(force_balance)
+                       & over & (best_gain > _NEG / 2) & (vw > 0))
+        # parity tie-break (avoid A<->B swap oscillation)
+        node_par = (jnp.arange(n) + parity) % 2 == 0
+        want = want & node_par
+        if active is not None:
+            want = want & active
+        proposal = jnp.where(want, best_tgt, labels)
+    with jax.named_scope("accept"):
+        new_labels = capped_accept(labels, proposal, vw, sizes, cap,
+                                   jnp.where(want, best_gain, _NEG))
+    with jax.named_scope("sizes"):
+        new_sizes = jnp.zeros((k,), sizes.dtype).at[new_labels].add(vw)
     return new_labels, new_sizes
 
 
@@ -266,30 +294,35 @@ def _cluster_lp_round(g: CooGraph, labels: jax.Array, cap: jax.Array,
                       key: jax.Array, i: jax.Array, moved: jax.Array,
                       iters: int):
     """Round ``i`` of ``iters`` clustering LP rounds → (new labels,
-    ``moved`` + the number of nodes it moved)."""
+    ``moved`` (iters,) with slot ``i`` set to the number of nodes it
+    moved)."""
     n = g.n_pad
     vw = g.vwgt
     sizes = jnp.zeros((n,), jnp.float32).at[labels].add(vw)
     k1, _ = jax.random.split(jax.random.split(key, iters)[i])
-    best_lab, best_aff, own_aff = _segment_affinity(g, labels, sizes, cap, k1)
+    with jax.named_scope("rating"):
+        best_lab, best_aff, own_aff = _segment_affinity(g, labels, sizes,
+                                                        cap, k1)
     improve = (best_aff > own_aff + _GAIN_EPS) & (best_lab < n)
     node_par = (jnp.arange(n) + i) % 2 == 0
     want = improve & node_par
     proposal = jnp.where(want, best_lab, labels).astype(labels.dtype)
     pri = jnp.where(want, best_aff - own_aff, _NEG)
-    new_labels = capped_accept(labels, proposal, vw, sizes, cap, pri)
-    return new_labels, moved + jnp.sum((new_labels != labels)
-                                       .astype(jnp.int32))
+    with jax.named_scope("accept"):
+        new_labels = capped_accept(labels, proposal, vw, sizes, cap, pri)
+    return new_labels, moved.at[i].set(
+        jnp.sum((new_labels != labels).astype(jnp.int32)))
 
 
 def _cluster_lp(g: CooGraph, labels0: jax.Array, cap: jax.Array,
                 key: jax.Array, iters: int):
-    """``iters`` clustering rounds → (labels, total node moves).
+    """``iters`` clustering rounds → (labels, (iters,) node moves per
+    round).
 
     The rounds are dispatched one compiled round at a time rather than as
     a device loop: the TPU compiler takes several times longer to build
     the round inside a loop than alone, and the dispatches are cheap."""
-    labels, moved = labels0, jnp.int32(0)
+    labels, moved = labels0, jnp.asarray(np.zeros(iters, np.int32))
     for i in range(iters):
         labels, moved = _cluster_lp_round(g, labels, cap, key, jnp.int32(i),
                                           moved, iters)
@@ -298,14 +331,23 @@ def _cluster_lp(g: CooGraph, labels0: jax.Array, cap: jax.Array,
 
 def size_constrained_lp(g: Graph, max_cluster_weight: float,
                         iters: int = 10, seed: int = 0,
-                        coo: Optional[CooGraph] = None) -> np.ndarray:
-    """The ``label_propagation`` program: returns a clustering (host ints)."""
+                        coo: Optional[CooGraph] = None,
+                        recorder=None) -> np.ndarray:
+    """The ``label_propagation`` program: returns a clustering (host ints).
+
+    An enabled ``recorder`` (default: the ambient one) gets the rounds'
+    ``coarsen/lp_rounds``, ``coarsen/lp_rounds_moved`` and
+    ``coarsen/lp_moves`` (`count_round_moves`)."""
     coo = coo if coo is not None else to_coo(g)
     n_pad = coo.n_pad
     # host-built constants: jnp.arange/jnp.full would each compile a
     # one-op program (iota / broadcast_in_dim) per shape
     labels0 = jnp.asarray(np.arange(n_pad, dtype=np.int32))
     cap = jnp.asarray(np.full(n_pad, max_cluster_weight, np.float32))
-    labels, _ = _cluster_lp(coo, labels0, cap, jax.random.PRNGKey(seed),
-                            iters)
-    return np.asarray(labels)[:g.n]
+    labels, moved = _cluster_lp(coo, labels0, cap,
+                                jax.random.PRNGKey(seed), iters)
+    out = np.asarray(labels)[:g.n]
+    rec = recorder if recorder is not None else obs.current()
+    if rec.enabled:
+        count_round_moves(rec, "coarsen/lp_", moved, 1)
+    return out

@@ -20,8 +20,9 @@ invariant (``view_build_count()`` is the back-compat alias) — the
 regression test pins view construction to O(levels), not O(levels×rounds).
 
 Observability (DESIGN.md §11): the engine emits hierarchical spans
-(hierarchy build, per-level coarsen, the initial tournament, per-level
-uncoarsen refinement, V-cycles, restarts), counters, and quality
+(hierarchy build, per-level coarsen with its cluster and contract steps,
+device-view builds, the initial tournament, per-level uncoarsen
+refinement, V-cycles, restarts), counters, and quality
 trajectories through the recorder resolved by `recorder_of` — either the
 medium's ``EngineParams.recorder`` or the ambient ``obs.use`` context.
 With no recorder installed every hook is the no-op `obs.NULL`; extra
@@ -129,8 +130,12 @@ class ViewCache:
 
     @property
     def views(self):
+        """The cached views; the first call builds them inside a ``views``
+        span, which covers the host build and the dispatch of the upload
+        (no device sync is added for it)."""
         if self._views is None:
-            self._views = self.build_views()
+            with recorder_of(self).span("views", n=self.n):
+                self._views = self.build_views()
             _note_view_build()
         return self._views
 
@@ -284,11 +289,13 @@ def build_hierarchy(medium: Medium, k: int, seed: int,
             with rec.span("coarsen", level=lvl, n=cur.n):
                 max_cw = max(1.0,
                              cur.total_vwgt() / (p.cluster_weight_factor * k))
-                clusters = cur.cluster(max_cw, seed + 31 * lvl,
-                                       protect=cur_protect)
+                with rec.span("cluster", level=lvl, n=cur.n):
+                    clusters = cur.cluster(max_cw, seed + 31 * lvl,
+                                           protect=cur_protect)
                 if cur_protect:
                     clusters = _signature_split(clusters, cur_protect)
-                coarse, cl = cur.contract(clusters)
+                with rec.span("contract", level=lvl, n=cur.n):
+                    coarse, cl = cur.contract(clusters)
             if coarse.n >= cur.n * p.stall_factor:
                 break
             if cur_protect:

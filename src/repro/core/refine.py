@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.csr import (Graph, CooGraph, EllGraph, neighbour_any,
                             to_coo, to_ell)
 from repro.core.partition import edge_cut_device, edge_cut, is_feasible
@@ -60,6 +61,11 @@ def _refine_scan(g: CooGraph, labels0: jax.Array, cap: jax.Array,
     ``nrounds`` (traced) masks trailing rounds to no-ops — a short search
     (e.g. multi-try's ``rounds//2``) keeps its exact ``split(key, r)`` key
     sequence while sharing the full-length compiled program.
+
+    → (labels, cut, moves): ``moves`` (rounds,) counts the vertices each
+    round moved (0 in masked rounds).  The round's steps carry the named
+    scopes ``affinity``, ``gain``, ``accept``, ``sizes`` (`kway_lp_round`),
+    ``reach`` and ``cut``.
     """
     n = g.n_pad
     vw = g.vwgt
@@ -83,23 +89,26 @@ def _refine_scan(g: CooGraph, labels0: jax.Array, cap: jax.Array,
         new_labels = jnp.where(live, prop_labels, labels)
         new_sizes = jnp.where(live, prop_sizes, sizes)
         moved = new_labels != labels
-        reach = neighbour_any(g, moved, ell)
-        active = active | reach | moved
-        cut = edge_cut_device(g, new_labels)
-        feas = jnp.max(new_sizes - cap) <= 1e-6
-        better = feas & (cut < best_cut)
-        best_cut = jnp.where(better, cut, best_cut)
-        best_labels = jnp.where(better, new_labels, best_labels)
+        with jax.named_scope("reach"):
+            reach = neighbour_any(g, moved, ell)
+            active = active | reach | moved
+        with jax.named_scope("cut"):
+            cut = edge_cut_device(g, new_labels)
+            feas = jnp.max(new_sizes - cap) <= 1e-6
+            better = feas & (cut < best_cut)
+            best_cut = jnp.where(better, cut, best_cut)
+            best_labels = jnp.where(better, new_labels, best_labels)
         return (new_labels, new_sizes, active, best_cut, best_labels,
-                parity + 1), cut
+                parity + 1), jnp.sum(moved.astype(jnp.int32))
 
-    (labels, sizes, _, best_cut, best_labels, _), cuts = jax.lax.scan(
+    (labels, sizes, _, best_cut, best_labels, _), moves = jax.lax.scan(
         body, (labels0, sizes0, active0, best_cut0, labels0, jnp.int32(0)),
         rkeys)
     # undo-to-best (KaFFPa semantics): return best feasible if one was seen
     have_best = jnp.isfinite(best_cut)
     out = jnp.where(have_best, best_labels, labels)
-    return out, jnp.where(have_best, best_cut, edge_cut_device(g, labels))
+    return (out, jnp.where(have_best, best_cut, edge_cut_device(g, labels)),
+            moves)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "rounds", "use_kernel"))
@@ -109,7 +118,8 @@ def _refine_scan_batch(g: CooGraph, labels0: jax.Array, cap: jax.Array,
                        active0: jax.Array, k: int, rounds: int,
                        ell: Optional[EllGraph] = None,
                        use_kernel: bool = False):
-    """THE k-way refinement program: everything routes through here."""
+    """THE k-way refinement program: everything routes through here.
+    → (labels, cuts, moves), one row each per candidate."""
     def one(lab0, rk, nr, z, f, a0):
         return _refine_scan(g, lab0, cap, rk, nr, k, rounds, z, f, a0,
                             ell=ell, use_kernel=use_kernel)
@@ -159,16 +169,20 @@ def _round_keys(key, rounds: int, rounds_bucket: int) -> np.ndarray:
 
 
 def _run_scan_batch(coo, cap_np, labs, rkeys, nrounds, zero, force, active,
-                    k, rounds_bucket, ell, use_kernel, batch_floor):
+                    k, rounds_bucket, ell, use_kernel, batch_floor,
+                    recorder=None):
     """Shared batched-entry plumbing: pow2-pad the batch dim, count bucket
-    pads and program-cache hits, run the one jitted program."""
+    pads and program-cache hits, run the one jitted program.  An enabled
+    ``recorder`` (default: the ambient one) gets the program's
+    ``refine/rounds``, ``refine/rounds_moved`` and ``refine/moves``
+    (`lp.count_round_moves`), read back after the labels."""
     from repro.core import multilevel as ML
     b = labs.shape[0]
     b_pad = batch_bucket(b, batch_floor)
     ML.note_bucket_pad(b_pad - b)
     ML.note_program("kway", coo.n_pad, coo.e_pad, k, rounds_bucket, b_pad,
                     use_kernel)
-    outs, _ = _refine_scan_batch(
+    outs, _, moves = _refine_scan_batch(
         coo, jnp.asarray(_pad_rows(labs, b_pad)),
         jnp.asarray(np.asarray(cap_np, np.float32)),
         jnp.asarray(_pad_rows(rkeys, b_pad)),
@@ -177,7 +191,11 @@ def _run_scan_batch(coo, cap_np, labs, rkeys, nrounds, zero, force, active,
         jnp.asarray(_pad_rows(force, b_pad)),
         jnp.asarray(_pad_rows(active, b_pad)),
         k, rounds_bucket, ell=ell, use_kernel=use_kernel)
-    return np.asarray(outs, dtype=np.int64)[:b]
+    outs = np.asarray(outs, dtype=np.int64)[:b]
+    rec = recorder if recorder is not None else obs.current()
+    if rec.enabled:
+        lp_mod.count_round_moves(rec, "refine/", moves, b)
+    return outs
 
 
 def refine_kway(g: Graph, part: np.ndarray, k: int, eps: float = 0.03,
@@ -188,7 +206,8 @@ def refine_kway(g: Graph, part: np.ndarray, k: int, eps: float = 0.03,
                 use_kernel: Optional[bool] = None,
                 ell: Optional[EllGraph] = None,
                 batch_floor: int = 1,
-                rounds_bucket: Optional[int] = None) -> np.ndarray:
+                rounds_bucket: Optional[int] = None,
+                recorder=None) -> np.ndarray:
     """Polish ``part``; never returns a worse feasible cut (undo-to-best).
 
     ``use_kernel=None`` resolves to the backend default (Pallas on TPU, COO
@@ -196,6 +215,7 @@ def refine_kway(g: Graph, part: np.ndarray, k: int, eps: float = 0.03,
     ``batch_floor`` pads the batch dim up to the medium's bucket so this
     single call reuses the tournament's compiled program; ``rounds_bucket``
     likewise pads the round schedule (extra rounds are masked no-ops).
+    ``recorder`` gets the round counters (`_run_scan_batch`).
     """
     if k <= 1 or g.n == 0:
         return part
@@ -211,7 +231,7 @@ def refine_kway(g: Graph, part: np.ndarray, k: int, eps: float = 0.03,
                            np.asarray([rounds]),
                            np.zeros(1, bool), np.asarray([force_balance]),
                            np.ones((1, coo.n_pad), bool), k, rb, ell,
-                           use_kernel, batch_floor)
+                           use_kernel, batch_floor, recorder)
     out = outs[0][:g.n]
     # paranoia: keep the better of (in, out) among feasible options
     if edge_cut(g, out) <= edge_cut(g, part) or force_balance:
@@ -226,7 +246,8 @@ def refine_kway_batch(g: Graph, parts: list, k: int, eps: float = 0.03,
                       use_kernel: Optional[bool] = None,
                       keys: Optional[np.ndarray] = None,
                       batch_floor: int = 1,
-                      rounds_bucket: Optional[int] = None) -> list:
+                      rounds_bucket: Optional[int] = None,
+                      recorder=None) -> list:
     """Refine several candidate partitions in one vmapped device call.
 
     The initial-partition tournament uses this so all tries share a single
@@ -254,7 +275,7 @@ def refine_kway_batch(g: Graph, parts: list, k: int, eps: float = 0.03,
                            np.full(len(parts), rounds),
                            np.zeros(len(parts), bool),
                            force, np.ones((len(parts), coo.n_pad), bool),
-                           k, rb, ell, use_kernel, batch_floor)
+                           k, rb, ell, use_kernel, batch_floor, recorder)
     outs = outs[:, :g.n]
     result = []
     for i, p in enumerate(parts):
@@ -271,7 +292,8 @@ def multi_try_refine(g: Graph, part: np.ndarray, k: int, eps: float = 0.03,
                      seed_frac: float = 0.05,
                      coo: Optional[CooGraph] = None,
                      batch_floor: int = 1,
-                     rounds_bucket: Optional[int] = None) -> np.ndarray:
+                     rounds_bucket: Optional[int] = None,
+                     recorder=None) -> np.ndarray:
     """Multi-try FM analogue: several localized searches from random boundary
     seeds; keeps the best feasible result."""
     if k <= 1 or g.n == 0:
@@ -298,7 +320,8 @@ def multi_try_refine(g: Graph, part: np.ndarray, k: int, eps: float = 0.03,
         outs = _run_scan_batch(coo, cap_np, labs, rkeys,
                                np.asarray([rounds]),
                                np.ones(1, bool), np.zeros(1, bool),
-                               active0, k, rb, None, False, batch_floor)
+                               active0, k, rb, None, False, batch_floor,
+                               recorder)
         out = outs[0][:g.n]
         c = edge_cut(g, out)
         if c < best_cut:

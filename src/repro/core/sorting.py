@@ -29,10 +29,12 @@ def sort_free() -> bool:
 
 def lexsort(keys: Sequence[jax.Array], bits: Sequence[int]) -> jax.Array:
     """Stable argsort by ``keys[-1]``, then ``keys[-2]``, … (jnp.lexsort
-    order).  ``keys[i]`` holds non-negative ints below ``2**bits[i]``."""
-    if not sort_free():
-        return jnp.lexsort(tuple(keys))
-    return radix_lexsort(keys, bits)
+    order).  ``keys[i]`` holds non-negative ints below ``2**bits[i]``.
+    Its ops carry the ``lexsort`` scope in the program's metadata."""
+    with jax.named_scope("lexsort"):
+        if not sort_free():
+            return jnp.lexsort(tuple(keys))
+        return radix_lexsort(keys, bits)
 
 
 def radix_lexsort(keys: Sequence[jax.Array],

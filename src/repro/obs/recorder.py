@@ -22,7 +22,8 @@ Engine code guards any extra objective evaluation behind
 
 ``annotate_xprof=True`` additionally wraps every span in a
 ``jax.profiler.TraceAnnotation`` so engine spans line up with XLA traces
-in a profiler session.
+in a profiler session: the annotation keeps the span's bare name, and its
+attributes (``level``, ``n``, ...) become the trace event's stats.
 """
 from __future__ import annotations
 
@@ -98,7 +99,7 @@ class _Span:
             ev["args"] = self.attrs
         rec._emit(ev)
         if rec._xprof is not None:
-            self._ann = rec._xprof(self.name)
+            self._ann = rec._xprof(self.name, **self.attrs)
             self._ann.__enter__()
         return self
 
